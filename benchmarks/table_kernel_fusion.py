@@ -52,6 +52,7 @@ from repro.core.round import FLConfig
 from repro.core.pipeline import build_update_pipeline
 from repro.core.secure_agg import masked_payload_bytes
 from repro.kernels import ops as kops
+from repro.launch.mesh import make_mesh
 from repro.models import sharding as sh
 
 K = 4                                   # commit slots (async buffer size)
@@ -171,7 +172,7 @@ def _sharded_rows(rng):
         print("sharded: skipped (single device; jax initialized before "
               "the device-count flag could apply)")
         return []
-    mesh = jax.make_mesh((2,), ("data",))
+    mesh = make_mesh((2,), ("data",))
     out = []
     for secure in (False, True):
         comp = CompressionConfig(quantize_bits=8, topk_frac=0.1,

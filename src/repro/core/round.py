@@ -208,19 +208,7 @@ def build_fl_round_step(loss_fn: Callable, client_opt: Optimizer,
         def client_body(carry, xs):
             acc, wsum, loss_sum = carry
             batch_c, w_c, m_c, idx, r = xs
-            # Sequential-mode GSPMD audit (PR 10, mirroring the PR 8 parallel
-            # -mode guard above): constraining activations over the POD axis
-            # inside this scan miscompiles the BACKWARD on pod-extent>1
-            # meshes — the primal loss stays bitwise-exact while mlstm-style
-            # gradients (e.g. an up-projection sharded ("data","model") or
-            # ("model",) on the last dim) come out O(1) wrong.  Minimal repro
-            # pinned in tests/test_mesh_small.py::test_pod_axis_grad_pin.
-            # Excluding POD from activation constraints (batch shards over
-            # `data` only, replicated across pods) restores float-accurate
-            # grads (~2e-5 worst-leaf rel, reassociation only); multi-pod
-            # batch layout belongs to pod_sequential anyway.
-            with shd.exclude_axes(shd.POD):
-                delta, loss = local_train(global_params, batch_c, r)
+            delta, loss = local_train(global_params, batch_c, r)
             wt = pipe.client_weight(w_c, m_c, loss)
             contrib = pipe.contribution(delta, wt, r, idx=idx, ids=ids,
                                         participation=mask, key=key)

@@ -135,10 +135,16 @@ def _secure_kernel(x_ref, w_ref, seeds_ref, coef_ref, base_ref, o_ref,
            + jax.lax.broadcasted_iota(jnp.uint32, (rows, block), 0)
            * np.uint32(block)
            + jax.lax.broadcasted_iota(jnp.uint32, (rows, block), 1))
+    # Each slot's WIRE word: its quantized word plus its masks
+    # coef[i,j] * PRF(seed[i,j]), summed mod 2^32 — the sum mask_total_u32
+    # forms for the oracle, so the bits agree.  Peers loop with scalar
+    # reads: Mosaic has no unsigned reduction over a peer axis.
     total = jnp.zeros((rows, block), jnp.uint32)
-    for i in range(K):     # static unroll: accumulate each slot's WIRE word
-        total = total + (qu[i] + mask_total_u32(seeds_ref[i], coef_ref[i],
-                                                idx))
+    for i in range(K):
+        total = total + qu[i]
+        for j in range(K):
+            cu = coef_ref[i, j].astype(jnp.uint32)   # two's complement
+            total = total + cu * hash_u32(idx * _GOLDEN + seeds_ref[i, j])
     summed = jax.lax.bitcast_convert_type(total, jnp.int32).astype(jnp.float32)
     o_ref[...] = (summed * scale[0]).astype(o_ref.dtype)
 

@@ -51,7 +51,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import fedprox_update as _fp
@@ -109,8 +108,8 @@ def _shard_rows_map(mesh, axes, fn, xb):
     zeros), so it is sliced off untouched."""
     n = math.prod(mesh.shape[a] for a in axes)
     xb, pad = _pad_rows(xb, n, 0)
-    y = shard_map(fn, mesh=mesh, in_specs=(P(axes, None),),
-                  out_specs=P(axes, None), check_rep=False)(xb)
+    y = jax.shard_map(fn, mesh=mesh, in_specs=(P(axes, None),),
+                      out_specs=P(axes, None), check_vma=False)(xb)
     return y[:-pad] if pad else y
 
 
@@ -127,9 +126,9 @@ def _shard_rows_reduce(mesh, axes, fn, xb, *consts):
     def body(xb_l, *cs):
         return fn(xb_l, sh.flat_shard_index(axes, mesh), *cs)
 
-    y = shard_map(body, mesh=mesh,
-                  in_specs=(P(None, axes, None),) + (P(),) * len(consts),
-                  out_specs=P(axes, None), check_rep=False)(xb, *consts)
+    y = jax.shard_map(body, mesh=mesh,
+                      in_specs=(P(None, axes, None),) + (P(),) * len(consts),
+                      out_specs=P(axes, None), check_vma=False)(xb, *consts)
     return y[:-pad] if pad else y
 
 

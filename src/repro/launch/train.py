@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 from pathlib import Path
 
 import jax
@@ -41,6 +42,22 @@ from repro.orchestrator import (AsyncOrchestrator, BatchedAsyncOrchestrator,
                                 split_fleet)
 from repro.orchestrator.straggler import expected_attempt_s
 from repro.sched import HybridAdapter, JobSpec, K8sAdapter, SlurmAdapter
+
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def init_compile_cache() -> str:
+    """Give JAX's persistent compilation cache a fixed home and return it.
+    A ``JAX_COMPILATION_CACHE_DIR`` from the environment wins (JAX reads it
+    itself, so nothing is set); otherwise ``<repo>/.jax_compile_cache``.
+    The directory is part of every cache key, so it is never derived from a
+    tmpdir, a pid or the clock.  Call before the first compile."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_ROOT / ".jax_compile_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
 
 # --engine auto crossover: below this fleet size the per-event engine wins
 # (no vmap padding / bucketing overhead on tiny fleets — see the committed
@@ -111,7 +128,10 @@ def render_jobs(fleet, out_dir: Path):
     return len(fleet)
 
 
-def main():
+def main(argv=None):
+    """Run one federated job from CLI-style ``argv`` (default: sys.argv)
+    and return the summary it prints."""
+    init_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="cifar10",
                     choices=["cifar10", "medmnist", "shakespeare"])
@@ -252,7 +272,7 @@ def main():
                          "event heap, buffer and RNG streams are restored)")
     ap.add_argument("--render-jobs", default="")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     fed, model, params, eval_fn = build_task(args.dataset, args.clients_pool,
                                              args.seed)
@@ -435,6 +455,7 @@ def main():
             "recovered_updates": orch.recovered_updates,
             "lost_to_faults": orch.lost_to_faults,
             "final_eval": orch.logs[-1].eval_metric if orch.logs else None,
+            "final_loss": orch.logs[-1].client_loss if orch.logs else None,
             "virtual_time_s": orch.clock,
             "updates_per_sim_s": orch.updates_per_sim_second,
             "mean_queue_wait_s": (float(np.mean([l.queue_wait_s
@@ -480,6 +501,7 @@ def main():
             "secure_agg": args.secure_agg,
             "rounds": args.rounds,
             "final_eval": orch.logs[-1].eval_metric if orch.logs else None,
+            "final_loss": orch.logs[-1].client_loss if orch.logs else None,
             "virtual_time_s": orch.virtual_clock,
             "mean_bytes_per_client_round":
                 orch.comm.mean_bytes_per_client_round(),
@@ -490,6 +512,7 @@ def main():
             "preempted_clients": sum(l.n_preempted for l in orch.logs),
         }
     print(json.dumps(summary, indent=1))
+    return summary
 
 
 if __name__ == "__main__":

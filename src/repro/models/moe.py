@@ -25,13 +25,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.7 (check_vma kwarg)
-    def shard_map(f, **kw):
-        kw["check_vma"] = kw.pop("check_rep", False)
-        return _shard_map(f, **kw)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import MoEConfig
 from repro.models import sharding as sh
@@ -186,10 +179,10 @@ def moe_apply(p, x, *, cfg: MoEConfig, act: str, mode: str = "gather_weights"):
                  f_axes=f_axes,
                  token_axes=tok_axes if mode == "gather_tokens" else (),
                  mode=mode)
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(x_spec, specs["router"], specs["w1"], specs["w3"], specs["w2"]),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["w1"], p["w3"], p["w2"])
     return out, aux

@@ -16,13 +16,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.7 (check_vma kwarg)
-    def shard_map(f, **kw):
-        kw["check_vma"] = kw.pop("check_rep", False)
-        return _shard_map(f, **kw)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import XLSTMConfig
 from repro.models import sharding as sh
@@ -273,7 +266,7 @@ def slstm_apply(p, x, *, n_heads: int, mode="train", state=None):
         # One shard_map over the whole block, moe-style: heads manual over
         # the model axis, tokens over whichever batch axes divide B.  Every
         # cotangent that crosses the boundary does so along a MENTIONED
-        # axis (tokens) or replicated params — with check_rep=False, an
+        # axis (tokens) or replicated params — with check_vma=False, an
         # output left unmentioned on an axis gets per-shard-inconsistent
         # cotangents whenever the incoming cotangent is sharded over it
         # (exactly what the batch-sharded residual stream produces), which
@@ -289,7 +282,7 @@ def slstm_apply(p, x, *, n_heads: int, mode="train", state=None):
         ws = tuple(p[f"w{g}"] for g in "ifzo")
         bs = tuple(p[f"b{g}"] for g in "ifzo")
         fn = partial(_slstm_block, model_axis=sh.MODEL, out_dtype=x.dtype)
-        out, carry = shard_map(
+        out, carry = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(P(tok, None, None),
                       tuple(P(None, sh.MODEL) for _ in ws),
@@ -299,7 +292,7 @@ def slstm_apply(p, x, *, n_heads: int, mode="train", state=None):
                       tuple(P(tok, sh.MODEL, None) for _ in carry0)),
             out_specs=(P(tok, None, None),
                        tuple(P(tok, sh.MODEL, None) for _ in carry0)),
-            check_rep=False,
+            check_vma=False,
         )(x, ws, bs, rp, p["down"], carry0)
         # Pin the output (and hence, through the constraint's transpose, its
         # cotangent) to exactly the sharding the shard_map declared: batch

@@ -3,7 +3,7 @@
 Run directly (NOT a pytest file — the XLA device count must be forced
 before jax initialises, so this runs as its own process):
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=2 \
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=2 \
         PYTHONPATH=src python tests/mesh_smoke.py
 
 Asserts the PR 10 gate-lift acceptance on the cheapest possible case:
@@ -19,7 +19,6 @@ if "--xla_force_host_platform_device_count" not in os.environ.get(
         "XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=2")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax                                                    # noqa: E402
 import jax.numpy as jnp                                       # noqa: E402
@@ -29,6 +28,7 @@ from repro.core import (AsyncConfig, CompressionConfig,       # noqa: E402
                         FLConfig, build_buffer_commit_step,
                         build_client_update_step, build_fl_round_step,
                         build_update_pipeline)
+from repro.launch.mesh import make_mesh                       # noqa: E402
 from repro.models import build_model, sharding as sh          # noqa: E402
 from repro.optim import (get_client_optimizer,                # noqa: E402
                          get_server_optimizer)
@@ -53,7 +53,7 @@ def main():
                               cfg.vocab, jnp.int32)
     batches = {"tokens": toks[..., :-1], "targets": toks[..., 1:]}
     copt, sopt = get_client_optimizer("sgd"), get_server_optimizer("fedavg")
-    mesh = jax.make_mesh((2,), ("data",))
+    mesh = make_mesh((2,), ("data",))
 
     with sh.use_mesh(mesh), mesh:
         assert build_update_pipeline(FLConfig()).fused, (

@@ -135,7 +135,8 @@ def test_parallel_under_mesh_requires_spmd_axes(setup):
     # loss) — the builder must reject it loudly at build time
     m, _, _ = setup
     from repro.models import sharding as sh
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     fl = FLConfig(num_clients=C, local_steps=H, client_lr=0.1,
                   client_exec="parallel")
     with sh.use_mesh(mesh):

@@ -182,7 +182,6 @@ def test_bucketed_tree_single_launch():
 _SHARD_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import json
 import jax
 import jax.numpy as jnp
@@ -191,6 +190,7 @@ import numpy as np
 from repro.core import FLConfig, build_update_pipeline
 from repro.core import secure_agg as sec
 from repro.kernels import ops as kops
+from repro.launch.mesh import make_mesh
 from repro.models import sharding as sh
 
 K = 4
@@ -215,7 +215,7 @@ ref = {
     "tree": kops.fused_secure_commit_tree(leaves, w, seeds, coef, bits=8),
 }
 
-mesh = jax.make_mesh((2,), ("data",))
+mesh = make_mesh((2,), ("data",))
 out = {}
 with sh.use_mesh(mesh):
     assert build_update_pipeline(FLConfig()).fused, "gate-lift regression"

@@ -18,7 +18,6 @@ ROOT = Path(__file__).resolve().parents[1]
 SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import json
 import jax
 import jax.numpy as jnp
@@ -28,10 +27,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config, reduced
 from repro.core import CompressionConfig, FLConfig, build_fl_round_step
 from repro.launch import specs as sp
+from repro.launch.mesh import make_mesh
 from repro.models import build_model, sharding as sh
 from repro.optim import get_client_optimizer, get_server_optimizer
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 assert len(jax.devices()) == 8
 
 cfg = reduced(get_config("%(arch)s"))
@@ -128,115 +128,12 @@ def run_case(arch: str, exec_mode: str, param_tol: float):
     # heads and the r* cotangents accumulate shard-locally (the GSPMD scan
     # transpose used to mis-accumulate them when r* was e-dim sharded).
     ("xlstm-125m", "parallel", 3e-2),
-    # xlstm/sequential is the case the PR 10 sequential-mode audit fixed:
-    # without the POD exclusion in round_sequential its mlstm grads come
-    # out O(1) wrong on this pod-extent-2 mesh (see test_pod_axis_grad_pin)
+    # xlstm/sequential guards the GSPMD backward of the mlstm grads on a
+    # pod-extent-2 mesh with the full activation constraints (POD included)
     ("xlstm-125m", "sequential", 3e-2),
 ])
 def test_sharded_round_matches_unsharded(arch, exec_mode, param_tol):
     run_case(arch, exec_mode, param_tol)
-
-
-# --------------------------------------------------------------------------
-# PR 10 sequential-mode GSPMD audit: pinned minimal repro.
-#
-# Root cause (bisected, see round.py round_sequential): jitting a direct
-# value_and_grad of the xlstm loss with params sharded by their full specs
-# on a mesh whose POD axis has extent > 1 miscompiles the BACKWARD — the
-# primal loss stays BITWISE-exact while mlstm gradients (worst leaf ~2.3
-# relative) are corrupt.  Characterisation:
-#   * needs pod extent > 1: meshes (1,2,2)/(1,1,2)/(1,2,1) are exact with
-#     the same full specs, at any batch size (even uneven b=1);
-#   * triggered by params whose LAST dim is sharded over batch-participating
-#     axes — e.g. mlstm up ("data","model") or ("model",)-style layouts with
-#     an extra ("data",) constraint; (data,None)/(None,model)/down/all-sLSTM
-#     layouts are clean; granite on the same mesh passes at 3e-2;
-#   * needs the real mlstm block structure (standalone matmul/scan chains
-#     do not reproduce) — i.e. an XLA GSPMD transpose bug, not repo math.
-# Mitigation (asserted here and applied in round_sequential): exclude POD
-# from activation constraints during sequential-mode local training —
-# restores grads to float accuracy (~2e-5 worst-leaf relative, ulp-level
-# reassociation from the different GSPMD reduction order).  If rel_bad
-# ever drops below 0.1, the upstream miscompile was fixed and the
-# exclusion can be reconsidered.
-# --------------------------------------------------------------------------
-PIN_SCRIPT = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-import json
-import jax
-import jax.numpy as jnp
-import numpy as np
-
-from repro.configs import get_config, reduced
-from repro.launch import specs as sp
-from repro.models import build_model, sharding as sh
-
-cfg = reduced(get_config("xlstm-125m"))
-m = build_model(cfg)
-params = m.init(jax.random.PRNGKey(0))
-toks = jax.random.randint(jax.random.PRNGKey(1), (2, 17), 0, cfg.vocab,
-                          jnp.int32)
-batch = {"tokens": toks[:, :-1], "targets": toks[:, 1:]}
-loss_ref, grads_ref = jax.value_and_grad(
-    lambda p: m.loss_fn(p, batch)[0])(params)
-
-
-def worst_rel(grads_m):
-    flat_r = jax.tree.leaves(grads_ref)
-    flat_m = jax.tree.leaves(grads_m)
-    return max(
-        float(np.abs(np.asarray(gr, np.float64)
-                     - np.asarray(gm, np.float64)).max()
-              / (np.abs(np.asarray(gr, np.float64)).max() + 1e-12))
-        for gr, gm in zip(flat_r, flat_m))
-
-
-def run(exclude_pod):
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-    with sh.use_mesh(mesh):
-        param_sh = sp.sanitize_specs(
-            jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                         params), m.logical_specs, mesh)
-        p_dev = jax.device_put(params, param_sh)
-        with mesh:
-            def fn(p, bt):
-                if exclude_pod:
-                    with sh.exclude_axes(sh.POD):
-                        return jax.value_and_grad(
-                            lambda q: m.loss_fn(q, bt)[0])(p)
-                return jax.value_and_grad(lambda q: m.loss_fn(q, bt)[0])(p)
-            loss_m, grads_m = jax.jit(fn, in_shardings=(param_sh, None))(
-                p_dev, batch)
-    return float(loss_m), worst_rel(grads_m)
-
-
-loss_bad, rel_bad = run(False)
-loss_fix, rel_fix = run(True)
-print(json.dumps({"loss_ref": float(loss_ref), "loss_bad": loss_bad,
-                  "loss_fix": loss_fix, "rel_bad": rel_bad,
-                  "rel_fix": rel_fix}))
-"""
-
-
-def test_pod_axis_grad_pin():
-    env = dict(os.environ, PYTHONPATH=str(ROOT / "src"))
-    env.pop("XLA_FLAGS", None)
-    out = subprocess.run([sys.executable, "-c", PIN_SCRIPT],
-                         capture_output=True, text=True, env=env, cwd=ROOT,
-                         timeout=900)
-    assert out.returncode == 0, out.stderr[-3000:]
-    res = json.loads(out.stdout.strip().splitlines()[-1])
-    # the miscompile corrupts ONLY the backward: primal loss bitwise-exact
-    assert res["loss_bad"] == res["loss_ref"], res
-    assert res["loss_fix"] == res["loss_ref"], res
-    # mitigation: POD excluded -> grads float-accurate (reassociation only)
-    assert res["rel_fix"] < 1e-3, res
-    # the pin: full specs on a pod-extent-2 mesh corrupt xlstm grads.  If
-    # this flips, the upstream XLA GSPMD transpose bug got fixed — the
-    # round_sequential POD exclusion can then be reconsidered.
-    assert res["rel_bad"] > 0.1, res
 
 
 # --------------------------------------------------------------------------
@@ -248,7 +145,6 @@ def test_pod_axis_grad_pin():
 FUSED_MESH_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import json
 import jax
 import jax.numpy as jnp
@@ -257,6 +153,7 @@ from repro.configs import get_config
 from repro.core import (AsyncConfig, CompressionConfig, FLConfig,
                         build_buffer_commit_step, build_client_update_step,
                         build_fl_round_step, build_update_pipeline)
+from repro.launch.mesh import make_mesh
 from repro.models import build_model, sharding as sh
 from repro.optim import get_client_optimizer, get_server_optimizer
 
@@ -270,7 +167,7 @@ toks = jax.random.randint(jax.random.PRNGKey(1), (C, H, b, S + 1), 0,
                           cfg.vocab, jnp.int32)
 batches = {"tokens": toks[..., :-1], "targets": toks[..., 1:]}
 DET = dict(quantize_bits=8, topk_frac=0.1, stochastic_rounding=False)
-mesh = jax.make_mesh(MESH_SHAPE, ("data", "model"))
+mesh = make_mesh(MESH_SHAPE, ("data", "model"))
 copt, sopt = get_client_optimizer("sgd"), get_server_optimizer("fedavg")
 report = {}
 

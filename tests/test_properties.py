@@ -245,7 +245,7 @@ def _buffers(draw):
     D = draw(st.integers(1, 12))
     d = draw(hnp.arrays(np.float32, (K, D), elements=floats))
     w = draw(hnp.arrays(np.float32, (K,),
-                        elements=st.floats(0.1, 5, width=32)))
+                        elements=st.floats(np.float32(0.1), 5, width=32)))
     m = np.asarray(draw(st.lists(st.integers(0, 1), min_size=K, max_size=K)),
                    np.float32)
     s = np.asarray(draw(st.lists(st.integers(0, 10), min_size=K, max_size=K)),

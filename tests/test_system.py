@@ -1,5 +1,9 @@
 """End-to-end system behaviour: the full orchestrated FL loop (Algorithm 1 +
 §4 optimizations) trains real models on non-IID synthetic data."""
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +13,7 @@ from repro.checkpoint import CheckpointManager
 from repro.core import CompressionConfig, FLConfig
 from repro.data import (FederatedDataset, cifar10_like, partition_by_class,
                         partition_by_group, shakespeare_like)
+from repro.launch.train import REPO_ROOT
 from repro.models import build_model
 from repro.models.cnn import CNN, CNNConfig
 from repro.configs import get_config
@@ -113,3 +118,40 @@ class TestCharLM:
         params, _ = orch.run(params, 8)
         losses = [l.client_loss for l in orch.logs]
         assert losses[-1] < losses[0] - 0.3, losses
+
+
+
+_CACHE_SCRIPT = r"""
+import sys
+import jax
+from repro.launch.train import init_compile_cache
+path = init_compile_cache()
+if sys.argv[1] == "compile":
+    jax.jit(lambda x: x * 3 + 1)(1.0).block_until_ready()
+print(path)
+print(jax.config.jax_compilation_cache_dir)
+"""
+
+
+def _cache_dirs(mode, **env_over):
+    env = dict(os.environ, PYTHONPATH=str(REPO_ROOT / "src"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.update(env_over)
+    out = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT, mode],
+                         capture_output=True, text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    return out.stdout.split()
+
+
+def test_compile_cache_placement(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR from the environment holds the persistent
+    compile cache, untouched; without it the cache goes to the fixed
+    <repo>/.jax_compile_cache (checked without compiling, so the test
+    writes nothing into the checkout)."""
+    given = tmp_path / "cc"
+    assert _cache_dirs("compile", JAX_COMPILATION_CACHE_DIR=str(given)) \
+        == [str(given)] * 2
+    assert any(given.iterdir())
+    default = str(REPO_ROOT / ".jax_compile_cache")
+    assert _cache_dirs("no-compile") == [default] * 2

@@ -23,7 +23,7 @@ def test_worker_round_trip(tmp_path):
     save_pytree(tmp_path / "global_round_0000.bin",
                 jax.tree.map(np.asarray, params))
 
-    env = dict(os.environ, PYTHONPATH=str(ROOT / "src"), JAX_PLATFORMS="cpu")
+    env = dict(os.environ, PYTHONPATH=str(ROOT / "src"))
     out = subprocess.run(
         [sys.executable, "-m", "repro.worker", "--client-id", "3",
          "--workdir", str(tmp_path), "--once", "--local-steps", "2",
