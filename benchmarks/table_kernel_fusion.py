@@ -4,8 +4,6 @@ Measures the one-pass Pallas commit path (core.pipeline, use_fused) against
 the unfused stage stack per (leaf-size x quantize-bits x secure_agg) cell:
 
   * achieved parity          max |fused - unfused| on the committed delta
-  * wall time fused/unfused  CPU interpret-mode walltimes — NOT TPU times;
-                             the bytes columns carry the roofline claim
   * predicted bytes-touched  costmodel.commit_bytes_touched fused vs the
                              per-stage unfused stack (acceptance: <= 0.5x)
   * masked wire bytes        secure_agg.masked_payload_bytes vs the plain
@@ -39,7 +37,6 @@ if "--xla_force_host_platform_device_count" not in os.environ.get(
                                + " --xla_force_host_platform_device_count=2")
 
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
@@ -58,19 +55,6 @@ from repro.models import sharding as sh
 K = 4                                   # commit slots (async buffer size)
 LEAF_SIZES = [1 << 16, 1 << 20]
 BITS = [4, 8]
-
-
-def _time(fn, *args, n=5):
-    # median of n fenced repeats after one warmup (compile + dispatch);
-    # the median resists the one-off GC/allocation hiccups that skew a
-    # mean on shared CI boxes
-    jax.block_until_ready(fn(*args))
-    reps = []
-    for _ in range(n):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        reps.append(time.perf_counter() - t0)
-    return float(np.median(reps))
 
 
 def _launches(fn, *args):
@@ -113,7 +97,6 @@ def _cell(n_elems, bits, secure, rng):
     fused, unfused = build(True), build(False)
     args = (deltas, weights, mask, staleness, key)
     launches = _launches(fused, *args)
-    t_f, t_u = _time(fused, *args), _time(unfused, *args)
     diff = float(jnp.max(jnp.abs(fused(*args)["w"] - unfused(*args)["w"])))
 
     pred_f = commit_bytes_touched(n_elems, K, quantize_bits=bits, topk=True,
@@ -128,8 +111,6 @@ def _cell(n_elems, bits, secure, rng):
     masked_wire = masked_payload_bytes(tree, quant_only, n_slots=K)
     return {
         "n_elems": n_elems, "bits": bits, "secure": secure,
-        "fused_s": t_f, "unfused_s": t_u,
-        "walltime_fused_x": t_f / t_u,
         "launches_fused": launches,
         "fused_vs_unfused_max_abs": diff,
         "pred_bytes_fused": pred_f, "pred_bytes_unfused": pred_u,
@@ -153,11 +134,10 @@ def _bucketing_row(rng, n_leaves=32):
     per_leaf = jax.jit(lambda ls: [kops.fused_plain_commit(
         l, w, s, 0.5, bits=8, k=26) for l in ls])
     l_b, l_p = _launches(bucketed, leaves), _launches(per_leaf, leaves)
-    t_b, t_p = _time(bucketed, leaves), _time(per_leaf, leaves)
     parity = max(float(jnp.max(jnp.abs(a - b)))
                  for a, b in zip(bucketed(leaves), per_leaf(leaves)))
     row = {"n_leaves": n_leaves, "launches_bucketed": l_b,
-           "launches_per_leaf": l_p, "bucketed_s": t_b, "per_leaf_s": t_p,
+           "launches_per_leaf": l_p,
            "bucketed_vs_per_leaf_max_abs": parity}
     print(f"bucketing: {n_leaves} leaves -> {l_b} launch(es) bucketed vs "
           f"{l_p} per-leaf, parity={parity:.2e}")
@@ -201,12 +181,10 @@ def _sharded_rows(rng):
             assert pipe_f.fused, "gate-lift regression: fused off under mesh"
             args = (deltas, weights, mask, key)
             launches = _launches(fused, *args)
-            t_f, t_u = _time(fused, *args), _time(unfused, *args)
             diff = float(jnp.max(jnp.abs(fused(*args)["w"]
                                          - unfused(*args)["w"])))
         out.append({"devices": 2, "mesh_axes": ["data"], "secure": secure,
                     "fused_stays_on": True, "launches_fused": launches,
-                    "sharded_fused_s": t_f, "sharded_unfused_s": t_u,
                     "sharded_parity_max_abs": diff})
         print(f"sharded: secure={int(secure)} parity={diff:.2e} "
               f"launches={launches} (2-device mesh, fused stayed on)")
@@ -225,7 +203,6 @@ def main():
                       f"parity={r['fused_vs_unfused_max_abs']:.2e} "
                       f"bytes-fused={r['pred_bytes_fused_x']:.3f}x "
                       f"wire-masked={r['masked_wire_x']:.3f}x "
-                      f"wall-fused={r['walltime_fused_x']:.2f}x "
                       f"launches={r['launches_fused']}")
     bucketing = _bucketing_row(rng)
     sharded = _sharded_rows(rng)
@@ -243,9 +220,10 @@ def main():
     save("table_kernel_fusion", {
         "rows": rows, "bucketing": bucketing, "sharded": sharded,
         "headline": headline, "n_slots": K,
-        "note": ("walltimes are CPU interpret-mode, not TPU; bytes columns "
-                 "are the analytic roofline (costmodel.commit_bytes_touched) "
-                 "and wire accounting (secure_agg.masked_payload_bytes)")})
+        "note": ("bytes columns are the analytic roofline "
+                 "(costmodel.commit_bytes_touched) and wire accounting "
+                 "(secure_agg.masked_payload_bytes); no device time is "
+                 "measured here")})
     return rows
 
 

@@ -67,7 +67,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.pipeline import build_update_pipeline, staleness_weights  # noqa: F401  (re-export)
-from repro.core.round import FLConfig, build_local_train, global_norm
+from repro.core.round import (FLConfig, build_local_train, global_norm,
+                              server_step)
 from repro.optim import Optimizer, ServerOptimizer
 
 
@@ -231,7 +232,8 @@ def build_buffer_commit_step(server_opt: ServerOptimizer, cfg: FLConfig,
         delta, w_eff, _ = pipe.combine(
             deltas, weights, mask, losses, rng, ids=ids,
             staleness=staleness, exponent=exponent)
-        new_params, new_state = server_opt.apply(params, delta, server_state)
+        new_params, new_state = server_step(server_opt, params, delta,
+                                            server_state)
         metrics = {
             "delta_norm": global_norm(delta),
             "n_updates": mask.sum(),
@@ -279,7 +281,8 @@ def build_chunked_commit_steps(server_opt: ServerOptimizer, cfg: FLConfig,
 
     def finalize(params, server_state, acc, wsum):
         delta = pipe.normalise(acc, wsum)
-        new_params, new_state = server_opt.apply(params, delta, server_state)
+        new_params, new_state = server_step(server_opt, params, delta,
+                                            server_state)
         return new_params, new_state, {"delta_norm": global_norm(delta)}
 
     return accumulate, finalize

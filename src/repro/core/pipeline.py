@@ -125,6 +125,7 @@ hide).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import TYPE_CHECKING
 
 import jax
@@ -149,6 +150,16 @@ def staleness_weights(staleness, exponent):
     the adaptive-alpha path feeds the controller's current value per
     commit."""
     return (1.0 + staleness) ** (-exponent)
+
+
+def _commit_stage(fn):
+    """Trace a stage under the ``fl.commit`` device scope, so that every
+    operation of the commit carries it in its ``op_name``."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope("fl.commit"):
+            return fn(*args, **kwargs)
+    return scoped
 
 
 class UpdatePipeline:
@@ -184,6 +195,7 @@ class UpdatePipeline:
         self.n_pods = n_pods
 
     # ------------------------------------------------------------- stage 1
+    @_commit_stage
     def compress(self, tree, rng):
         return compress_tree(tree, self.cfg.compression, rng)
 
@@ -228,6 +240,7 @@ class UpdatePipeline:
             return (d.astype(jnp.float32) * wb).sum(0)
         return jax.tree.map(one, stacked)
 
+    @_commit_stage
     def normalise(self, summed, w_raw_sum):
         denom = jnp.maximum(w_raw_sum, 1e-12)
         return jax.tree.map(lambda s: (s / denom.astype(s.dtype)), summed)
@@ -237,6 +250,7 @@ class UpdatePipeline:
         dt = jnp.dtype(self.cfg.accum_dtype)
         return jax.tree.map(lambda p: jnp.zeros(p.shape, dt), params_like)
 
+    @_commit_stage
     def contribution(self, delta, wt, rng, idx=None, ids=None,
                      participation=None, key=None):
         """One slot's contribution to the running sum: compress ->
@@ -252,10 +266,12 @@ class UpdatePipeline:
             pre = sec.mask_slot(key, ids, participation, idx, pre)
         return pre
 
+    @_commit_stage
     def accum_add(self, acc, contrib):
         return jax.tree.map(lambda a, c: a + c.astype(a.dtype), acc, contrib)
 
     # --------------------------------------------------------- combinators
+    @_commit_stage
     def combine_unnormalised(self, deltas, weights, mask, losses, rng,
                              ids=None, staleness=None, exponent=None):
         """compress -> weight/discount -> (secure_mask) -> weighted sum,
@@ -348,6 +364,7 @@ class UpdatePipeline:
             block=comp.block, use_pallas=self.fused, noise_rng=nr)
         return jax.tree.unflatten(treedef, out)
 
+    @_commit_stage
     def combine(self, deltas, weights, mask, losses, rng, ids=None,
                 staleness=None, exponent=None):
         """The full batched stack over [K, ...] slot deltas.
@@ -389,6 +406,7 @@ class UpdatePipeline:
         sums = jax.tree.map(pod_sums, deltas)          # [P, ...] un-normalised
         return self.combine_pods(sums, w_raw.sum(), rng)
 
+    @_commit_stage
     def combine_pods(self, pod_sums, w_total, rng, compressed=False):
         """Cross-pod tail of the stack: compress each pod's partial sum,
         secure-mask BETWEEN PODS (privacy at site granularity — each

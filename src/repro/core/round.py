@@ -102,6 +102,12 @@ def constrain_like(tree, shardings):
     return jax.tree.map(apply, tree, shardings)
 
 
+def server_step(server_opt: ServerOptimizer, params, delta, state):
+    """The server optimizer's step, under the ``fl.server_step`` scope."""
+    with jax.named_scope("fl.server_step"):
+        return server_opt.apply(params, delta, state)
+
+
 def build_local_train(loss_fn: Callable, client_opt: Optimizer, cfg: FLConfig,
                       param_shardings=None):
     """Returns local_train(global_params, batches_H, rng) -> (delta, mean_loss).
@@ -111,6 +117,10 @@ def build_local_train(loss_fn: Callable, client_opt: Optimizer, cfg: FLConfig,
     and fusable into the Pallas fedprox_update kernel."""
 
     def local_train(global_params, batches, rng):
+        with jax.named_scope("fl.local_train"):
+            return _local_train(global_params, batches, rng)
+
+    def _local_train(global_params, batches, rng):
         opt0 = client_opt.init(global_params)
 
         def step(carry, xs):
@@ -190,7 +200,8 @@ def build_fl_round_step(loss_fn: Callable, client_opt: Optimizer,
                                   spmd_axis_name=client_spmd_axes)(
             global_params, client_batches, rngs)
         delta, _, _ = pipe.combine(deltas, weights, mask, losses, rng)
-        new_params, new_state = server_opt.apply(global_params, delta, server_state)
+        new_params, new_state = server_step(server_opt, global_params,
+                                            delta, server_state)
         metrics = {
             "client_loss": (losses * mask).sum() / jnp.maximum(mask.sum(), 1),
             "delta_norm": global_norm(delta),
@@ -221,7 +232,8 @@ def build_fl_round_step(loss_fn: Callable, client_opt: Optimizer,
             client_body, (zero, jnp.float32(0.0), jnp.float32(0.0)),
             (client_batches, weights, mask, ids, rngs))
         delta = pipe.normalise(acc, wsum)
-        new_params, new_state = server_opt.apply(global_params, delta, server_state)
+        new_params, new_state = server_step(server_opt, global_params,
+                                            delta, server_state)
         metrics = {
             "client_loss": loss_sum / jnp.maximum(mask.sum(), 1),
             "delta_norm": global_norm(delta),
@@ -280,8 +292,8 @@ def build_fl_round_step(loss_fn: Callable, client_opt: Optimizer,
             pod_body, spmd_axis_name=client_spmd_axes)(
             resh, w2, m2, jax.random.split(rng, P))
         delta = pipe.combine_pods(accs, wsums.sum(), rng, compressed=True)
-        new_params, new_state = server_opt.apply(global_params, delta,
-                                                 server_state)
+        new_params, new_state = server_step(server_opt, global_params,
+                                            delta, server_state)
         metrics = {
             "client_loss": loss_sums.sum() / jnp.maximum(mask.sum(), 1),
             "delta_norm": global_norm(delta),

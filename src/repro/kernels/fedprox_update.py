@@ -34,6 +34,7 @@ def fedprox_update_flat(w, g, w0, lr: float, mu: float, interpret: bool):
     spec = pl.BlockSpec((tile,), lambda i: (i,))
     return pl.pallas_call(
         functools.partial(_kernel, lr=lr, mu=mu),
+        name="fl_fedprox_update",
         grid=(n // tile,),
         in_specs=[spec, spec, spec],
         out_specs=spec,

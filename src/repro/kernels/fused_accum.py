@@ -37,11 +37,15 @@ def fused_accum_blocks(xb, w, s, alpha, interpret: bool):
     rows = R if interpret else min(ROWS_TILE, R)
     rows_pad = (-R) % rows
     if rows_pad:
-        xb = jnp.concatenate(
-            [xb, jnp.zeros((K, rows_pad, block), xb.dtype)], axis=1)
+        # zero rows fill the last tile: part of packing the slot stack, and
+        # XLA merges this concatenate with the bucket's own (ops.pack_blocks)
+        with jax.named_scope("fl.commit.pack"):
+            xb = jnp.concatenate(
+                [xb, jnp.zeros((K, rows_pad, block), xb.dtype)], axis=1)
     Rp = R + rows_pad
     y = pl.pallas_call(
         _kernel,
+        name="fl_accum",
         grid=(Rp // rows,),
         in_specs=[
             pl.BlockSpec((K, rows, block), lambda i: (0, i, 0)),

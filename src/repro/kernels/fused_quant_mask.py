@@ -157,17 +157,27 @@ def _rows_tiling(R: int, interpret: bool):
     return rows, (-R) % rows
 
 
+def _fill_last_tile(xb, rows_pad: int):
+    """Zero rows that fill the last tile.  They are part of packing the slot
+    stack: XLA merges this concatenate with the bucket's own
+    (``ops.pack_blocks``), so it carries the same scope."""
+    K, _, block = xb.shape
+    with jax.named_scope("fl.commit.pack"):
+        return jnp.concatenate(
+            [xb, jnp.zeros((K, rows_pad, block), xb.dtype)], axis=1)
+
+
 def plain_commit_blocks(xb, w, s, alpha, *, bits: int, k: int,
                         interpret: bool):
     """xb: [K, R, block] f32 -> [R, block] f32 reduced leaf."""
     K, R, block = xb.shape
     rows, rows_pad = _rows_tiling(R, interpret)
     if rows_pad:
-        xb = jnp.concatenate(
-            [xb, jnp.zeros((K, rows_pad, block), xb.dtype)], axis=1)
+        xb = _fill_last_tile(xb, rows_pad)
     Rp = R + rows_pad
     y = pl.pallas_call(
         functools.partial(_plain_kernel, bits=bits, k=k),
+        name="fl_plain_commit",
         grid=(Rp // rows,),
         in_specs=[
             pl.BlockSpec((K, rows, block), lambda i: (0, i, 0)),
@@ -190,11 +200,11 @@ def secure_commit_blocks(xb, w_eff, seeds, coef, base, *, bits: int, k: int,
     K, R, block = xb.shape
     rows, rows_pad = _rows_tiling(R, interpret)
     if rows_pad:
-        xb = jnp.concatenate(
-            [xb, jnp.zeros((K, rows_pad, block), xb.dtype)], axis=1)
+        xb = _fill_last_tile(xb, rows_pad)
     Rp = R + rows_pad
     y = pl.pallas_call(
         functools.partial(_secure_kernel, bits=bits, k=k),
+        name="fl_secure_commit",
         grid=(Rp // rows,),
         in_specs=[
             pl.BlockSpec((K, rows, block), lambda i: (0, i, 0)),

@@ -162,12 +162,12 @@ def quantize_dequant(x, *, bits: int = 8, block: int = 256):
 
 @functools.lru_cache(maxsize=None)
 def _quantize_dequant_c(mesh, axes, bits, block):
-    def f(x):
+    def quantize_dequant(x):
         xb, meta = _as_blocks(x, block)
         run = lambda b: _q.quantize_dequant_blocks(b, bits, _interpret())
         y = run(xb) if mesh is None else _shard_rows_map(mesh, axes, run, xb)
         return _from_blocks(y, meta, x.shape, x.dtype)
-    return jax.jit(f)
+    return jax.jit(quantize_dequant)
 
 
 def topk_sparsify(x, *, k: int, block: int = 256):
@@ -178,14 +178,14 @@ def topk_sparsify(x, *, k: int, block: int = 256):
 
 @functools.lru_cache(maxsize=None)
 def _topk_sparsify_c(mesh, axes, k, block):
-    def f(x):
+    def topk_sparsify(x):
         xb, meta = _as_blocks(x, block)
         # padded zero blocks: threshold 0 keeps everything -> zeros stay
         # zero.  OK.
         run = lambda b: _tk.topk_sparsify_blocks(b, k, _interpret())
         y = run(xb) if mesh is None else _shard_rows_map(mesh, axes, run, xb)
         return _from_blocks(y, meta, x.shape, x.dtype)
-    return jax.jit(f)
+    return jax.jit(topk_sparsify)
 
 
 @functools.partial(jax.jit, static_argnames=("lr", "mu"))
@@ -246,20 +246,22 @@ def pack_blocks(leaves, block):
     collapses from O(#leaves) to one.  Returns (bucket, metas, row
     counts)."""
     blocked, metas, rows = [], [], []
-    for leaf in leaves:
-        xb, meta = _stack_blocks(leaf, block)
-        blocked.append(xb)
-        metas.append(meta)
-        rows.append(xb.shape[1])
-    return jnp.concatenate(blocked, axis=1), metas, rows
+    with jax.named_scope("fl.commit.pack"):
+        for leaf in leaves:
+            xb, meta = _stack_blocks(leaf, block)
+            blocked.append(xb)
+            metas.append(meta)
+            rows.append(xb.shape[1])
+        return jnp.concatenate(blocked, axis=1), metas, rows
 
 
 def unpack_sums(y, metas, rows, dtype=jnp.float32):
     """[R_total, block] summed bucket -> the per-leaf summed leaves."""
     out, r0 = [], 0
-    for meta, r in zip(metas, rows):
-        out.append(_unstack_sum(y[r0:r0 + r], meta, dtype))
-        r0 += r
+    with jax.named_scope("fl.commit.unpack"):
+        for meta, r in zip(metas, rows):
+            out.append(_unstack_sum(y[r0:r0 + r], meta, dtype))
+            r0 += r
     return out
 
 
@@ -333,12 +335,12 @@ def fused_accum_tree(leaves, w, staleness, exponent, *, block: int = 256):
 
 @functools.lru_cache(maxsize=None)
 def _fused_accum_tree_c(mesh, axes, block):
-    def f(leaves, w, s, a):
+    def fused_accum_tree(leaves, w, s, a):
         xb, metas, rows = pack_blocks(leaves, block)
         wv, sv, av = _slot_vectors(w, s, a, xb.shape[0])
         return unpack_sums(_accum_rows(mesh, axes, xb, wv, sv, av),
                            metas, rows)
-    return jax.jit(f)
+    return jax.jit(fused_accum_tree)
 
 
 def fused_plain_commit_tree(leaves, w, staleness, exponent, *, bits: int,
@@ -353,12 +355,12 @@ def fused_plain_commit_tree(leaves, w, staleness, exponent, *, bits: int,
 
 @functools.lru_cache(maxsize=None)
 def _fused_plain_tree_c(mesh, axes, bits, k, block):
-    def f(leaves, w, s, a):
+    def fused_plain_commit_tree(leaves, w, s, a):
         xb, metas, rows = pack_blocks(leaves, block)
         wv, sv, av = _slot_vectors(w, s, a, xb.shape[0])
         return unpack_sums(_plain_rows(mesh, axes, xb, wv, sv, av, bits, k),
                            metas, rows)
-    return jax.jit(f)
+    return jax.jit(fused_plain_commit_tree)
 
 
 def fused_secure_commit_tree(leaves, w_eff, seeds, coef, *, bits: int,
@@ -376,13 +378,13 @@ def fused_secure_commit_tree(leaves, w_eff, seeds, coef, *, bits: int,
 
 @functools.lru_cache(maxsize=None)
 def _fused_secure_tree_c(mesh, axes, bits, k, block, use_pallas):
-    def f(leaves, w_eff, seeds, coef, noise_rng):
+    def fused_secure_commit_tree(leaves, w_eff, seeds, coef, noise_rng):
         xb, metas, rows = pack_blocks(leaves, block)
         wv = w_eff.astype(jnp.float32).reshape(xb.shape[0], 1)
         y = _secure_body(mesh, axes, use_pallas, bits, k, xb, wv, seeds,
                          coef, jnp.uint32(0), noise_rng)
         return unpack_sums(y, metas, rows)
-    return jax.jit(f)
+    return jax.jit(fused_secure_commit_tree)
 
 
 # ---------------------------------------------------- per-leaf entry points
@@ -397,12 +399,12 @@ def fused_accum(x, w, staleness, exponent, *, block: int = 256):
 
 @functools.lru_cache(maxsize=None)
 def _fused_accum_c(mesh, axes, block):
-    def f(x, w, s, a):
+    def fused_accum(x, w, s, a):
         xb, meta = _stack_blocks(x, block)
         wv, sv, av = _slot_vectors(w, s, a, xb.shape[0])
         return _unstack_sum(_accum_rows(mesh, axes, xb, wv, sv, av), meta,
                             jnp.float32)
-    return jax.jit(f)
+    return jax.jit(fused_accum)
 
 
 def fused_plain_commit(x, w, staleness, exponent, *, bits: int, k: int,
@@ -419,12 +421,12 @@ def fused_plain_commit(x, w, staleness, exponent, *, bits: int, k: int,
 
 @functools.lru_cache(maxsize=None)
 def _fused_plain_c(mesh, axes, bits, k, block):
-    def f(x, w, s, a):
+    def fused_plain_commit(x, w, s, a):
         xb, meta = _stack_blocks(x, block)
         wv, sv, av = _slot_vectors(w, s, a, xb.shape[0])
         return _unstack_sum(_plain_rows(mesh, axes, xb, wv, sv, av, bits, k),
                             meta, jnp.float32)
-    return jax.jit(f)
+    return jax.jit(fused_plain_commit)
 
 
 def fused_secure_commit(x, w_eff, seeds, coef, base, *, bits: int, k: int = 0,
@@ -445,13 +447,13 @@ def fused_secure_commit(x, w_eff, seeds, coef, base, *, bits: int, k: int = 0,
 
 @functools.lru_cache(maxsize=None)
 def _fused_secure_c(mesh, axes, bits, k, block, use_pallas):
-    def f(x, w_eff, seeds, coef, base, noise_rng):
+    def fused_secure_commit(x, w_eff, seeds, coef, base, noise_rng):
         xb, meta = _stack_blocks(x, block)
         wv = w_eff.astype(jnp.float32).reshape(xb.shape[0], 1)
         y = _secure_body(mesh, axes, use_pallas, bits, k, xb, wv, seeds,
                          coef, base, noise_rng)
         return _unstack_sum(y, meta, jnp.float32)
-    return jax.jit(f)
+    return jax.jit(fused_secure_commit)
 
 
 # ---------------------------------------------------------------------------

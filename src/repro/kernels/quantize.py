@@ -43,6 +43,7 @@ def quantize_dequant_blocks(xb, bits: int, interpret: bool):
         xb = jnp.concatenate([xb, jnp.zeros((rows_pad, block), xb.dtype)])
     y = pl.pallas_call(
         functools.partial(_kernel, bits=bits),
+        name="fl_quantize",
         grid=((R + rows_pad) // rows,),
         in_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rows, block), lambda i: (i, 0)),

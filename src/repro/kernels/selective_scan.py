@@ -42,6 +42,7 @@ def selective_scan_chunk_kernel(a, b, h0, interpret: bool):
     assert D % dt == 0
     hs, hl = pl.pallas_call(
         _kernel,
+        name="fl_selective_scan",
         grid=(B, D // dt),
         in_specs=[
             pl.BlockSpec((1, L, dt, N), lambda bi, di: (bi, 0, di, 0)),

@@ -54,6 +54,7 @@ def topk_sparsify_blocks(xb, k: int, interpret: bool):
         xb = jnp.concatenate([xb, jnp.zeros((rows_pad, block), xb.dtype)])
     y = pl.pallas_call(
         functools.partial(_kernel, k=k),
+        name="fl_topk",
         grid=((R + rows_pad) // rows,),
         in_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((rows, block), lambda i: (i, 0)),

@@ -42,6 +42,7 @@ from repro.orchestrator.fault import (RECOVERABLE_FAULTS, FaultConfig,
                                       FaultInjector)
 from repro.orchestrator.selection import get_selection
 from repro.orchestrator.straggler import StragglerPolicy
+from repro.spans import span
 
 
 @dataclass
@@ -218,14 +219,16 @@ class AsyncOrchestrator:
     # --------------------------------------------------------- phase timers
     @contextmanager
     def _timed(self, phase: str):
-        """Attribute elapsed host wall-clock to ``phase``.  Nested phases
-        (a host_sync inside train, train inside dispatch) book their own
+        """Attribute elapsed host wall-clock to ``phase``, under the span
+        ``fl.async.<phase>`` on the profiler's clock.  Nested phases (a
+        host_sync inside train, train inside dispatch) book their own
         time; the outer phase gets elapsed minus whatever inner phases
         accrued, so the four counters partition the wall clock."""
         snap = dict(self._phase)
         t0 = perf_counter()
         try:
-            yield
+            with span(f"fl.async.{phase}"):
+                yield
         finally:
             inner = sum(self._phase[k] - snap[k] for k in snap)
             self._phase[phase] += perf_counter() - t0 - inner
@@ -537,8 +540,19 @@ class AsyncOrchestrator:
 
     def _do_commit(self, params, server_state, at_time: float,
                    timeout: bool = False):
-        t0 = perf_counter()
-        snap = dict(self._phase)
+        with self._timed("commit"):
+            params, server_state, log = self._apply_commit(
+                params, server_state, at_time, timeout)
+        # flush the phase accounting since the previous commit into its log
+        log.phase_wall = {k: round(v, 6) for k, v in self._phase.items()}
+        log.phase_wall["host_syncs"] = self._host_syncs
+        self._phase = {k: 0.0 for k in self._phase}
+        self._host_syncs = 0
+        return params, server_state
+
+    def _apply_commit(self, params, server_state, at_time: float,
+                      timeout: bool):
+        """Commit the buffer: (params, server_state, its CommitLog)."""
         self._materialize_for_commit()
         ups = [u for u, _ in self._buffer]
         stal = [self.version - u.dispatch_version for u in ups]
@@ -589,15 +603,7 @@ class AsyncOrchestrator:
         self._buffer = []
         self._buffer_bytes = 0
         self._buffer_t = np.empty(0)
-        # everything since the previous commit not booked to an inner phase
-        # is commit work; flush the window's phase accounting into the log
-        inner = sum(self._phase[k] - snap[k] for k in snap)
-        self._phase["commit"] += perf_counter() - t0 - inner
-        log.phase_wall = {k: round(v, 6) for k, v in self._phase.items()}
-        log.phase_wall["host_syncs"] = self._host_syncs
-        self._phase = {k: 0.0 for k in self._phase}
-        self._host_syncs = 0
-        return params, server_state
+        return params, server_state, log
 
     def _flush_timeouts(self, params, server_state, now: float):
         """Commit a partial buffer whose oldest update has waited >= T.

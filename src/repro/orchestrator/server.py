@@ -33,6 +33,7 @@ from repro.orchestrator.fault import FaultConfig, FaultInjector
 from repro.orchestrator.registry import ClientInfo
 from repro.orchestrator.selection import get_selection
 from repro.orchestrator.straggler import StragglerPolicy, apply_mitigation
+from repro.spans import span
 
 
 @dataclass
@@ -102,62 +103,74 @@ class Orchestrator:
         return self._server_opt.init(params)
 
     def run_round(self, rnd: int, params, server_state):
-        C = self.fl.num_clients
-        selected = self.selection.select(self.fleet, C, rnd)
-        clients = [self.fleet[c] for c in selected]
+        with span("fl.round", round=rnd):
+            return self._run_round(rnd, params, server_state)
 
-        # --- simulate system behaviour (host-side) ---
-        down_bytes, up_bytes = self._payload_bytes_cache(params)
-        execs = self.backend.execute_round(
-            clients, self.flops_per_client_round, up_bytes,
-            self.virtual_clock)
-        times = np.asarray([e.duration_s for e in execs])
-        mask, duration = apply_mitigation(times, self.straggler)
-        self.fault_injector.step_round()
-        mask = mask * self.fault_injector.survive_mask(
-            clients, include_preempt=not self.backend.handles_preemption)
-        if self.backend.handles_preemption:
-            # spot reclaims originate from the scheduler's own event stream
-            mask = mask * np.asarray([0.0 if e.preempted else 1.0
-                                      for e in execs])
+    def _run_round(self, rnd: int, params, server_state):
+        with span("fl.round.simulate"):
+            # who takes part, and how long each takes (host-side model)
+            C = self.fl.num_clients
+            selected = self.selection.select(self.fleet, C, rnd)
+            clients = [self.fleet[c] for c in selected]
+            down_bytes, up_bytes = self._payload_bytes_cache(params)
+            execs = self.backend.execute_round(
+                clients, self.flops_per_client_round, up_bytes,
+                self.virtual_clock)
+            times = np.asarray([e.duration_s for e in execs])
+            mask, duration = apply_mitigation(times, self.straggler)
+            self.fault_injector.step_round()
+            mask = mask * self.fault_injector.survive_mask(
+                clients, include_preempt=not self.backend.handles_preemption)
+            if self.backend.handles_preemption:
+                # spot reclaims originate from the scheduler's own event
+                # stream
+                mask = mask * np.asarray([0.0 if e.preempted else 1.0
+                                          for e in execs])
 
-        # --- data + weights ---
-        batches = self.fed_data.sample_round(selected, self.fl.local_steps,
-                                             self.batch_size)
-        batches = jax.tree.map(jnp.asarray, batches)
-        weights = jnp.asarray([max(self.fed_data.client_size(c), 1)
-                               for c in selected], jnp.float32)
-        jmask = jnp.asarray(mask, jnp.float32)
+        with span("fl.round.data"):
+            batches = self.fed_data.sample_round(
+                selected, self.fl.local_steps, self.batch_size)
+            batches = jax.tree.map(jnp.asarray, batches)
+            weights = jnp.asarray([max(self.fed_data.client_size(c), 1)
+                                   for c in selected], jnp.float32)
+            jmask = jnp.asarray(mask, jnp.float32)
 
-        # --- the jit'd Algorithm-1 round ---
-        self.jrng, r = jax.random.split(self.jrng)
-        params, server_state, metrics = self._round_step(
-            params, server_state, batches, weights, jmask, r)
+        with span("fl.round.dispatch"):
+            # the jit'd Algorithm-1 round
+            self.jrng, r = jax.random.split(self.jrng)
+            params, server_state, metrics = self._round_step(
+                params, server_state, batches, weights, jmask, r)
 
-        # --- accounting (links charged by PLACEMENT site, not home site) ---
-        bytes_up = 0
-        for ci, c in enumerate(clients):
-            link = link_for_site(execs[ci].site or c.site)
-            self.comm.log(rnd, c.cid, "down", down_bytes, link)
-            if mask[ci] > 0:
-                t = self.comm.log(rnd, c.cid, "up", up_bytes, link)
-                bytes_up += up_bytes
-            c.record(mask[ci] > 0, float(times[ci]), rnd)
-        self.virtual_clock += duration
-        # barrier closed: straggler jobs cut by the mitigation are abandoned
-        self.backend.end_round(self.virtual_clock)
+        with span("fl.round.fetch"):
+            # the round's one wait on the device
+            client_loss = float(metrics["client_loss"])
+            delta_norm = float(metrics["delta_norm"])
 
-        log = RoundLog(
-            rnd=rnd, selected=selected, participated=int(mask.sum()),
-            duration_s=duration,
-            client_loss=float(metrics["client_loss"]),
-            delta_norm=float(metrics["delta_norm"]),
-            bytes_up=bytes_up,
-            mean_queue_wait_s=float(np.mean([e.queue_wait_s for e in execs]))
-            if execs else 0.0,
-            n_overflow=sum(e.overflowed for e in execs),
-            n_preempted=sum(e.preempted for e in execs))
-        self.logs.append(log)
+        with span("fl.round.account"):
+            # links charged by PLACEMENT site, not home site
+            bytes_up = 0
+            for ci, c in enumerate(clients):
+                link = link_for_site(execs[ci].site or c.site)
+                self.comm.log(rnd, c.cid, "down", down_bytes, link)
+                if mask[ci] > 0:
+                    self.comm.log(rnd, c.cid, "up", up_bytes, link)
+                    bytes_up += up_bytes
+                c.record(mask[ci] > 0, float(times[ci]), rnd)
+            self.virtual_clock += duration
+            # barrier closed: straggler jobs cut by the mitigation are
+            # abandoned
+            self.backend.end_round(self.virtual_clock)
+
+            log = RoundLog(
+                rnd=rnd, selected=selected, participated=int(mask.sum()),
+                duration_s=duration, client_loss=client_loss,
+                delta_norm=delta_norm, bytes_up=bytes_up,
+                mean_queue_wait_s=float(np.mean([e.queue_wait_s
+                                                 for e in execs]))
+                if execs else 0.0,
+                n_overflow=sum(e.overflowed for e in execs),
+                n_preempted=sum(e.preempted for e in execs))
+            self.logs.append(log)
         return params, server_state, log
 
     def _payload_bytes_cache(self, params):

@@ -11,6 +11,7 @@ process at a time may load the TPU library, and every test worker imports
 this file.  Keep these tests in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +25,11 @@ from repro.kernels import quantize as q
 from repro.kernels import topk_sparsify as tk
 
 K, BLOCK, BITS, TOPK = 4, 256, 8, 26
+KERNEL_NAMES = {"fused_accum_blocks": "fl_accum",
+                "plain_commit_blocks": "fl_plain_commit",
+                "secure_commit_blocks": "fl_secure_commit",
+                "quantize_dequant_blocks": "fl_quantize",
+                "topk_sparsify_blocks": "fl_topk"}
 
 
 @pytest.fixture(scope="module")
@@ -102,5 +108,32 @@ def test_kernel_compiles_for_v5e(name, one_chip, bucket_rows,
     fn, shapes = _kernel_case(name, bucket_rows)
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's instruction carries its stable name, which a trace's
+    # operation names show
+    kernel = KERNEL_NAMES[name]
+    assert re.search(rf"%{kernel}(\.\d+)? = [^\n]*custom-call", text), kernel
+
+
+def test_padded_bucket_is_packing(one_chip, no_persistent_cache):
+    """XLA merges the bucket's concatenate (``kops.pack_blocks``) with the
+    kernel wrapper's zero rows that fill its last tile, and the merged
+    operations carry one op_name: both sit under ``fl.commit.pack``, so a
+    trace counts the whole assembly of the bucket as packing."""
+    f32, u32, i32 = jnp.float32, jnp.uint32, jnp.int32
+
+    def commit(leaves, w, seeds, coef, base):
+        xb = kops.pack_blocks(leaves, BLOCK)[0]
+        return fqm.secure_commit_blocks(xb, w, seeds, coef, base, bits=BITS,
+                                        k=TOPK, interpret=False)
+
+    # 10 + 1 rows of 256: the wrapper adds 5 zero rows to fill a tile of 8
+    shapes = [[((K, 5, 300), f32), ((K, 100), f32)], ((K, 1), f32),
+              ((K, K), u32), ((K, K), i32), ((1, 1), u32)]
+    args = jax.tree.map(
+        lambda sd: jax.ShapeDtypeStruct(*sd, sharding=one_chip), shapes,
+        is_leaf=lambda x: isinstance(x, tuple) and isinstance(x[0], tuple))
+    text = jax.jit(commit).lower(*args).compile().as_text()
+    names = re.findall(r'op_name="([^"]*concatenate)"', text)
+    assert names and all("/fl.commit.pack/" in n for n in names), names
