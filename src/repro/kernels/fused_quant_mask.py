@@ -6,7 +6,9 @@ The unfused pipeline (core/pipeline.py) materializes a full model-sized
 compress -> weight -> secure_mask -> aggregate.  Each stage is elementwise
 or a slot reduction, i.e. pure HBM bandwidth, so fusing them into a single
 kernel that reads each slot once and writes the reduced leaf once is the
-whole win.  Two kernel variants over one blocked [K, rows, block] tile:
+whole win.  Two kernel variants over a blocked [K, R, block] slot stack,
+which the grid walks in tiles of many rows and each kernel body in 8-row
+strips (``_rows_tiling``):
 
   * ``_plain_kernel`` — per-slot top-k, per-slot per-block symmetric
     quantize (identical algebra to the unfused core.compression stages),
@@ -50,8 +52,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-ROWS_TILE = 8
+STRIP_ROWS = 8                    # rows a kernel body works on at once
+STEP_IN_BYTES = 2 << 20           # f32 input tile of one grid step
 N_ITERS = 32                      # top-k threshold bisection iterations
 _GOLDEN = np.uint32(0x9E3779B9)   # element-index mixing constant
 
@@ -98,19 +102,24 @@ def topk_threshold_mask(mag, k: int):
 
 def _plain_kernel(x_ref, w_ref, s_ref, a_ref, o_ref, *, bits: int, k: int):
     """top-k -> per-slot per-block quantize -> discounted weighted sum."""
-    x = x_ref[...].astype(jnp.float32)               # [K, rows, block]
-    if k:
-        x = jnp.where(topk_threshold_mask(jnp.abs(x), k), x, 0.0)
-    if bits:
-        qmax = 2.0 ** (bits - 1) - 1
-        scale = jnp.max(jnp.abs(x), axis=-1, keepdims=True) / qmax
-        scale = jnp.where(scale == 0, 1.0, scale)
-        x = jnp.clip(jnp.round(x / scale), -qmax - 1, qmax) * scale
     w = w_ref[...].astype(jnp.float32)               # [K, 1]
     s = s_ref[...].astype(jnp.float32)               # [K, 1]
     a = a_ref[0, 0].astype(jnp.float32)
     w_eff = w * (1.0 + s) ** (-a)
-    o_ref[...] = (x * w_eff[:, :, None]).sum(0).astype(o_ref.dtype)
+
+    def strip(r0):
+        x = x_ref[:, pl.ds(r0, STRIP_ROWS), :].astype(jnp.float32)
+        if k:
+            x = jnp.where(topk_threshold_mask(jnp.abs(x), k), x, 0.0)
+        if bits:
+            qmax = 2.0 ** (bits - 1) - 1
+            scale = jnp.max(jnp.abs(x), axis=-1, keepdims=True) / qmax
+            scale = jnp.where(scale == 0, 1.0, scale)
+            x = jnp.clip(jnp.round(x / scale), -qmax - 1, qmax) * scale
+        o_ref[pl.ds(r0, STRIP_ROWS), :] = (
+            (x * w_eff[:, :, None]).sum(0).astype(o_ref.dtype))
+
+    _for_each_strip(x_ref.shape[1], strip)
 
 
 def _secure_kernel(x_ref, w_ref, seeds_ref, coef_ref, base_ref, o_ref,
@@ -119,42 +128,67 @@ def _secure_kernel(x_ref, w_ref, seeds_ref, coef_ref, base_ref, o_ref,
     pairwise mask -> sum -> dequantize.  Every slot must quantize onto ONE
     grid (the commit-common per-block scale) or the integer masks could
     not cancel in the sum."""
-    x = x_ref[...].astype(jnp.float32)               # [K, rows, block]
-    K, rows, block = x.shape
-    if k:
-        x = jnp.where(topk_threshold_mask(jnp.abs(x), k), x, 0.0)
+    K, rows, block = x_ref.shape
     w = w_ref[...].astype(jnp.float32)               # [K, 1] eff. weights
-    y = x * w[:, :, None]
     qmax = 2.0 ** (bits - 1) - 1
-    scale = jnp.max(jnp.abs(y), axis=(0, 2), keepdims=True) / qmax  # [1,r,1]
-    scale = jnp.where(scale == 0, 1.0, scale)
-    q = jnp.clip(jnp.round(y / scale), -qmax - 1, qmax).astype(jnp.int32)
-    qu = jax.lax.bitcast_convert_type(q, jnp.uint32)
-    off = (pl.program_id(0) * (rows * block)).astype(jnp.uint32)
-    idx = (off + base_ref[0, 0]
-           + jax.lax.broadcasted_iota(jnp.uint32, (rows, block), 0)
-           * np.uint32(block)
-           + jax.lax.broadcasted_iota(jnp.uint32, (rows, block), 1))
-    # Each slot's WIRE word: its quantized word plus its masks
-    # coef[i,j] * PRF(seed[i,j]), summed mod 2^32 — the sum mask_total_u32
-    # forms for the oracle, so the bits agree.  Peers loop with scalar
-    # reads: Mosaic has no unsigned reduction over a peer axis.
-    total = jnp.zeros((rows, block), jnp.uint32)
-    for i in range(K):
-        total = total + qu[i]
-        for j in range(K):
-            cu = coef_ref[i, j].astype(jnp.uint32)   # two's complement
-            total = total + cu * hash_u32(idx * _GOLDEN + seeds_ref[i, j])
-    summed = jax.lax.bitcast_convert_type(total, jnp.int32).astype(jnp.float32)
-    o_ref[...] = (summed * scale[0]).astype(o_ref.dtype)
+    # Pair seeds and two's-complement coefficients, read from SMEM once a
+    # step: every strip of the step reuses them.
+    seeds = [[seeds_ref[i, j] for j in range(K)] for i in range(K)]
+    coefs = [[coef_ref[i, j].astype(jnp.uint32) for j in range(K)]
+             for i in range(K)]
+    row0 = pl.program_id(0) * rows                   # first row of the step
+
+    def strip(r0):
+        x = x_ref[:, pl.ds(r0, STRIP_ROWS), :].astype(jnp.float32)
+        if k:
+            x = jnp.where(topk_threshold_mask(jnp.abs(x), k), x, 0.0)
+        y = x * w[:, :, None]
+        scale = jnp.max(jnp.abs(y), axis=(0, 2), keepdims=True) / qmax
+        scale = jnp.where(scale == 0, 1.0, scale)    # [1, STRIP_ROWS, 1]
+        q = jnp.clip(jnp.round(y / scale), -qmax - 1, qmax).astype(jnp.int32)
+        qu = jax.lax.bitcast_convert_type(q, jnp.uint32)
+        off = ((row0 + r0) * block).astype(jnp.uint32)
+        idx = (off + base_ref[0, 0]
+               + jax.lax.broadcasted_iota(jnp.uint32, (STRIP_ROWS, block), 0)
+               * np.uint32(block)
+               + jax.lax.broadcasted_iota(jnp.uint32, (STRIP_ROWS, block), 1))
+        # Each slot's WIRE word: its quantized word plus its masks
+        # coef[i,j] * PRF(seed[i,j]), summed mod 2^32 — the sum
+        # mask_total_u32 forms for the oracle, so the bits agree.  Peers
+        # loop over scalars: Mosaic has no unsigned reduction over a peer
+        # axis.
+        total = jnp.zeros((STRIP_ROWS, block), jnp.uint32)
+        for i in range(K):
+            total = total + qu[i]
+            for j in range(K):
+                total = total + coefs[i][j] * hash_u32(idx * _GOLDEN
+                                                       + seeds[i][j])
+        summed = jax.lax.bitcast_convert_type(total, jnp.int32)
+        o_ref[pl.ds(r0, STRIP_ROWS), :] = (
+            summed.astype(jnp.float32) * scale[0]).astype(o_ref.dtype)
+
+    _for_each_strip(rows, strip)
 
 
-def _rows_tiling(R: int, interpret: bool):
-    """Interpret mode runs the whole stack as one grid step (a vectorised
-    jnp evaluation — a Python grid loop over hundreds of tiles would crawl
-    on CPU); the TPU path tiles rows for VMEM."""
-    rows = R if interpret else min(ROWS_TILE, R)
+def _rows_tiling(K: int, R: int, block: int):
+    """(rows of one grid step, zero rows that fill the last tile).  A step
+    takes as many whole strips as fit ``STEP_IN_BYTES`` of its f32 input
+    (the pipeline holds two such tiles in VMEM), and no more strips than
+    the rows need: each step pays a fixed cost, so fewer, wider steps."""
+    fit = max(1, STEP_IN_BYTES // (K * block * 4 * STRIP_ROWS)) * STRIP_ROWS
+    rows = min(fit, -(-R // STRIP_ROWS) * STRIP_ROWS)
     return rows, (-R) % rows
+
+
+def _for_each_strip(rows: int, body):
+    """Call ``body(r0)`` for each strip of a step's tile, ``r0`` its first
+    row: a strip's values stay in vector registers, where a whole tile's
+    would spill to VMEM."""
+    def step(s, carry):
+        body(pl.multiple_of(s * STRIP_ROWS, STRIP_ROWS))
+        return carry
+
+    jax.lax.fori_loop(0, rows // STRIP_ROWS, step, 0)
 
 
 def _fill_last_tile(xb, rows_pad: int):
@@ -171,7 +205,7 @@ def plain_commit_blocks(xb, w, s, alpha, *, bits: int, k: int,
                         interpret: bool):
     """xb: [K, R, block] f32 -> [R, block] f32 reduced leaf."""
     K, R, block = xb.shape
-    rows, rows_pad = _rows_tiling(R, interpret)
+    rows, rows_pad = _rows_tiling(K, R, block)
     if rows_pad:
         xb = _fill_last_tile(xb, rows_pad)
     Rp = R + rows_pad
@@ -198,7 +232,7 @@ def secure_commit_blocks(xb, w_eff, seeds, coef, base, *, bits: int, k: int,
     coef: [K, K] int32 in {-1, 0, +1}; base: [1, 1] uint32 leaf offset into
     the commit-wide element index space.  Returns [R, block] f32."""
     K, R, block = xb.shape
-    rows, rows_pad = _rows_tiling(R, interpret)
+    rows, rows_pad = _rows_tiling(K, R, block)
     if rows_pad:
         xb = _fill_last_tile(xb, rows_pad)
     Rp = R + rows_pad
@@ -209,9 +243,9 @@ def secure_commit_blocks(xb, w_eff, seeds, coef, base, *, bits: int, k: int,
         in_specs=[
             pl.BlockSpec((K, rows, block), lambda i: (0, i, 0)),
             pl.BlockSpec((K, 1), lambda i: (0, 0)),
-            pl.BlockSpec((K, K), lambda i: (0, 0)),
-            pl.BlockSpec((K, K), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((rows, block), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Rp, block), jnp.float32),

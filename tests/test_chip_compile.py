@@ -4,7 +4,8 @@ Interpret mode (every other kernel test) cannot see what the TPU's compiler
 refuses: unsupported vector ops, misaligned tiles, VMEM limits.  These tests
 compile each kernel with ``interpret=False`` for a described ``v5e:2x2``
 topology (no chip attached) at the shape of a real commit: the leaf-bucketed
-``xlstm-125m`` delta, K=4 slots, 256-wide blocks.
+``xlstm-125m`` delta, K=4 slots (and K=8 for the secure kernel), 256-wide
+blocks.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -75,7 +76,7 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _kernel_case(name, R):
+def _kernel_case(name, R, K=K):
     """(kernel with interpret off, its argument shapes and dtypes)."""
     f32, u32, i32 = jnp.float32, jnp.uint32, jnp.int32
     stack, vec, one = ((K, R, BLOCK), f32), ((K, 1), f32), ((1, 1), f32)
@@ -100,12 +101,14 @@ def _kernel_case(name, R):
     }[name]
 
 
-@pytest.mark.parametrize("name", [
-    "fused_accum_blocks", "plain_commit_blocks", "secure_commit_blocks",
-    "quantize_dequant_blocks", "topk_sparsify_blocks"])
-def test_kernel_compiles_for_v5e(name, one_chip, bucket_rows,
+@pytest.mark.parametrize("name,slots", [
+    pytest.param(n, K, id=n) for n in (
+        "fused_accum_blocks", "plain_commit_blocks", "secure_commit_blocks",
+        "quantize_dequant_blocks", "topk_sparsify_blocks")] + [
+    pytest.param("secure_commit_blocks", 8, id="secure_commit_blocks-K8")])
+def test_kernel_compiles_for_v5e(name, slots, one_chip, bucket_rows,
                                  no_persistent_cache):
-    fn, shapes = _kernel_case(name, bucket_rows)
+    fn, shapes = _kernel_case(name, bucket_rows, slots)
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
@@ -137,3 +140,18 @@ def test_padded_bucket_is_packing(one_chip, no_persistent_cache):
     text = jax.jit(commit).lower(*args).compile().as_text()
     names = re.findall(r'op_name="([^"]*concatenate)"', text)
     assert names and all("/fl.commit.pack/" in n for n in names), names
+
+
+def test_secure_commit_steps_at_bucket(bucket_rows):
+    """The secure kernel's grid at the xlstm-125m bucket with K=8 slots:
+    256-row steps (2 MiB of f32 input) of 32 strips, 2,567 steps a commit
+    where 8-row steps took 82,144."""
+    fn, shapes = _kernel_case("secure_commit_blocks", bucket_rows, 8)
+    jaxpr = jax.make_jaxpr(fn)(*[jax.ShapeDtypeStruct(s, dt)
+                                 for s, dt in shapes])
+    grids = [e.params["grid_mapping"].grid for e in jaxpr.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert bucket_rows == 657_147
+    assert fqm._rows_tiling(8, bucket_rows, BLOCK) == (256, 5)
+    assert grids == [(2567,)]
+    assert 16 * grids[0][0] <= 82_144

@@ -29,6 +29,7 @@ from repro.core import (AsyncConfig, CompressionConfig, FLConfig,
                         build_buffer_commit_step, build_client_update_step,
                         build_fl_round_step, build_update_pipeline)
 from repro.core import secure_agg as sec
+from repro.kernels import fused_quant_mask as fqm
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.models import build_model
@@ -178,6 +179,107 @@ def test_bucketed_tree_single_launch():
     assert kops.KERNEL_LAUNCHES == len(leaves)
 
 
+# ---------------------------------------- wide grid steps over 8-row strips
+STEP_ROWS, STEP_R = 16, 37       # 2 strips a step; 3 steps, the last ragged
+
+
+@pytest.fixture
+def small_steps(monkeypatch):
+    """Shrink the step's input budget so a [K, STEP_R, 256] stack takes
+    STEP_ROWS-row grid steps: 2 strips each, 3 steps, 11 fill rows."""
+    monkeypatch.setattr(fqm, "STEP_IN_BYTES", K * 256 * 4 * STEP_ROWS)
+    assert fqm._rows_tiling(K, STEP_R, 256) == (STEP_ROWS, 11)
+
+
+def _grids(fn, *args):
+    """Grid of each pallas_call ``fn`` launches, read from its jaxpr."""
+    return [e.params["grid_mapping"].grid
+            for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+            if e.primitive.name == "pallas_call"]
+
+
+def _bits(a):
+    return np.asarray(jax.lax.bitcast_convert_type(a, jnp.uint32))
+
+
+def _secure_slots(seed, part=(1.0, 1.0, 0.0, 1.0)):
+    rng = np.random.default_rng(seed)
+    xb = jnp.asarray(rng.normal(size=(K, STEP_R, 256)).astype(np.float32)
+                     * 0.01)
+    part = jnp.asarray(part, jnp.float32)
+    w = jnp.asarray(rng.uniform(0.5, 2.0, (K, 1)).astype(np.float32))
+    ids = jnp.arange(1, K + 1, dtype=jnp.uint32)
+    seeds = sec.pair_seeds(jax.random.PRNGKey(seed), ids)
+    return xb, w * part[:, None], seeds, sec.pair_coef_int(ids, part)
+
+
+def _one_sided(coef):
+    """Keep each pair's mask on one slot only: the masks no longer cancel,
+    so the output carries every pair's mask words at every element index."""
+    return jnp.triu(coef)
+
+
+@pytest.mark.parametrize("kernel,k", [("secure", 0), ("secure", 26),
+                                      ("plain", 0), ("plain", 26)])
+def test_wide_steps_match_oracle_bitwise(small_steps, kernel, k):
+    """Several grid steps of several strips, the last step ragged, compute
+    the jitted oracle's bits: each strip's element index, scale and top-k
+    are those of its own rows."""
+    xb, w, seeds, coef = _secure_slots(21 + k)
+    if kernel == "secure":
+        base = jnp.asarray([[7]], jnp.uint32)
+        # one-sided masks: the output then reads each element's index
+        coef = _one_sided(coef)
+        run = lambda x: fqm.secure_commit_blocks(   # noqa: E731
+            x, w, seeds, coef, base, bits=8, k=k, interpret=True)
+        want = jax.jit(lambda x: kref.fused_secure_commit_ref(
+            x, w, seeds, coef, 7, 8, k=k))(xb)
+    else:
+        s = jnp.asarray([[0.0], [1.0], [3.0], [2.0]], jnp.float32)
+        a = jnp.asarray([[0.5]], jnp.float32)
+        run = lambda x: fqm.plain_commit_blocks(    # noqa: E731
+            x, w, s, a, bits=8, k=k, interpret=True)
+        want = jax.jit(lambda x: kref.fused_plain_commit_ref(
+            x, w, s, a, 8, k=k))(xb)
+    assert _grids(run, xb) == [(3,)]
+    np.testing.assert_array_equal(_bits(run(xb)), _bits(want))
+
+
+def test_masks_cancel_across_steps_and_strips(small_steps):
+    """The uint32 masks cancel bitwise across step and strip boundaries,
+    with a non-participating slot unwound: masked == coef-zeroed."""
+    xb, w, seeds, coef = _secure_slots(22)
+    base = jnp.asarray([[2**32 - 5000]], jnp.uint32)  # index wraps mod 2^32
+
+    def run(c):
+        return fqm.secure_commit_blocks(xb, w, seeds, c, base, bits=8, k=0,
+                                        interpret=True)
+
+    masked, unmasked = run(coef), run(jnp.zeros_like(coef))
+    assert _grids(run, coef) == [(3,)]
+    np.testing.assert_array_equal(_bits(masked), _bits(unmasked))
+
+
+@pytest.mark.parametrize("r1", [5, 8, 13, 16])
+def test_nonzero_base_keeps_global_index(small_steps, r1):
+    """A stack's rows from ``r1`` on, committed alone with ``base`` moved
+    on by r1 * block, reproduce the whole stack's rows bitwise: the mask
+    stream depends on the global element index, not on which step or
+    strip a row falls in (what the row-sharded mesh path relies on).
+    One-sided masks keep the stream in the output."""
+    xb, w, seeds, coef = _secure_slots(23, part=(1.0, 1.0, 1.0, 1.0))
+    coef = _one_sided(coef)
+
+    def run(x, base):
+        return fqm.secure_commit_blocks(
+            x, w, seeds, coef, jnp.asarray([[base]], jnp.uint32), bits=8,
+            k=0, interpret=True)
+
+    whole = run(xb, 11)
+    tail = run(xb[:, r1:], 11 + r1 * 256)
+    np.testing.assert_array_equal(_bits(tail), _bits(whole[r1:]))
+
+
 # ------------------------------------- sharded == unsharded, bitwise (PR 10)
 _SHARD_SCRIPT = r"""
 import os
@@ -189,11 +291,15 @@ import numpy as np
 
 from repro.core import FLConfig, build_update_pipeline
 from repro.core import secure_agg as sec
+from repro.kernels import fused_quant_mask as fqm
 from repro.kernels import ops as kops
 from repro.launch.mesh import make_mesh
 from repro.models import sharding as sh
 
 K = 4
+# 16-row grid steps of 2 strips: the 37 rows of xs take 3 steps unsharded
+# and 2 per shard (19 rows each), the last step ragged either way
+fqm.STEP_IN_BYTES = K * 256 * 4 * 16
 rng = np.random.default_rng(7)
 # 2049 elements -> 9 blocks of 256: odd row count forces the shard_map
 # wrappers through their pad-to-shard-multiple path
@@ -205,6 +311,8 @@ part = jnp.asarray([1.0, 1.0, 0.0, 1.0], jnp.float32)
 seeds = sec.pair_seeds(jax.random.PRNGKey(3), ids)
 coef = sec.pair_coef_int(ids, part)
 leaves = [x, jnp.asarray(rng.normal(size=(K, 3, 130)).astype(np.float32))]
+xs = jnp.asarray(rng.normal(size=(K, 37 * 256 - 100)).astype(np.float32)
+                 * 0.01)
 
 ref = {
     "quant": kops.quantize_dequant(x[0], bits=8),
@@ -213,6 +321,12 @@ ref = {
     "plain": kops.fused_plain_commit(x, w, s, 0.5, bits=8, k=26),
     "secure": kops.fused_secure_commit(x, w, seeds, coef, 7, bits=8),
     "tree": kops.fused_secure_commit_tree(leaves, w, seeds, coef, bits=8),
+    "plain_steps": kops.fused_plain_commit(xs, w, s, 0.5, bits=8, k=26),
+    "secure_steps": kops.fused_secure_commit(xs, w, seeds, coef, 7, bits=8),
+    # one-sided masks leave each element's mask words, hence its global
+    # index, in the output
+    "secure_steps_index": kops.fused_secure_commit(xs, w, seeds,
+                                                   jnp.triu(coef), 7, bits=8),
 }
 
 mesh = make_mesh((2,), ("data",))
@@ -227,6 +341,11 @@ with sh.use_mesh(mesh):
         "secure": kops.fused_secure_commit(x, w, seeds, coef, 7, bits=8),
         "tree": kops.fused_secure_commit_tree(leaves, w, seeds, coef,
                                               bits=8),
+        "plain_steps": kops.fused_plain_commit(xs, w, s, 0.5, bits=8, k=26),
+        "secure_steps": kops.fused_secure_commit(xs, w, seeds, coef, 7,
+                                                 bits=8),
+        "secure_steps_index": kops.fused_secure_commit(
+            xs, w, seeds, jnp.triu(coef), 7, bits=8),
     }
     for name in ref:
         out[name] = max(float(jnp.abs(a - b).max()) for a, b in zip(
@@ -238,6 +357,10 @@ with sh.use_mesh(mesh):
     unmasked = kops.fused_secure_commit(x, w * part, seeds,
                                         jnp.zeros_like(coef), 0, bits=8)
     out["mask_cancel"] = float(jnp.abs(masked - unmasked).max())
+    masked = kops.fused_secure_commit(xs, w * part, seeds, coef, 0, bits=8)
+    unmasked = kops.fused_secure_commit(xs, w * part, seeds,
+                                        jnp.zeros_like(coef), 0, bits=8)
+    out["mask_cancel_steps"] = float(jnp.abs(masked - unmasked).max())
 print(json.dumps(out))
 """
 
